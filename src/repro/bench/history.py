@@ -4,8 +4,9 @@
 the record the runner produced (name, samples, CI bounds, phases)
 stamped with the run id, unix time, git sha and an **environment
 fingerprint** — host, machine, CPU count, python/jax versions, jax
-backend, Pallas flag. Baselines are only ever selected from rows whose
-fingerprint matches the current environment byte-for-byte: timings
+backend, kernel path (``ops.use_pallas``). Baselines are only ever
+selected from rows whose fingerprint matches the current environment
+byte-for-byte: timings
 from a 2-core laptop say nothing about a 4-core CI runner, and gating
 across them would manufacture regressions. CI normalizes its
 ephemeral hostnames via ``REPRO_BENCH_HOST``.
@@ -49,10 +50,12 @@ def fingerprint() -> Dict[str, object]:
     overrides the hostname (CI runners are ephemeral but uniform)."""
     try:
         import jax
+        from repro.kernels.ops import use_pallas
         jax_ver = jax.__version__
         backend = jax.default_backend()
+        kernels = use_pallas() or "jnp"
     except Exception:       # noqa: BLE001 — fingerprint works without jax
-        jax_ver, backend = "none", "none"
+        jax_ver, backend, kernels = "none", "none", "none"
     return {
         "host": os.environ.get("REPRO_BENCH_HOST") or platform.node(),
         "machine": platform.machine(),
@@ -60,7 +63,7 @@ def fingerprint() -> Dict[str, object]:
         "python": platform.python_version(),
         "jax": jax_ver,
         "backend": backend,
-        "pallas": os.environ.get("REPRO_USE_PALLAS", "0"),
+        "kernels": kernels,
     }
 
 

@@ -31,7 +31,7 @@ def _with_server(cfg: EnvConfig, actions, srv=None):
 
 def device_only(cfg: EnvConfig, tables: ProfileTables, state, rng=None):
     """Lightweight version, run everything locally (last cut)."""
-    n = cfg.n_uavs
+    n = state["model_id"].shape[0]
     a = jnp.stack([jnp.zeros((n,), jnp.int32),
                    jnp.full((n,), tables.n_cuts - 1, jnp.int32)], -1)
     return _with_server(cfg, a)
@@ -39,7 +39,7 @@ def device_only(cfg: EnvConfig, tables: ProfileTables, state, rng=None):
 
 def full_offload(cfg: EnvConfig, tables: ProfileTables, state, rng=None):
     """Heavy version, cut as early as possible."""
-    n = cfg.n_uavs
+    n = state["model_id"].shape[0]
     j = (tables.version_valid[state["model_id"]].sum(-1) - 1).astype(jnp.int32)
     return _with_server(cfg, jnp.stack([j, jnp.zeros((n,), jnp.int32)], -1))
 
@@ -50,7 +50,7 @@ def random_policy(cfg: EnvConfig, tables: ProfileTables, state, rng):
     bias toward low version indices whenever a model has fewer versions
     than the padded table width; randint takes a per-device maxval, so
     sample [0, nv) directly."""
-    n = cfg.n_uavs
+    n = state["model_id"].shape[0]
     k1, k2, k3 = jax.random.split(rng, 3)
     nv = tables.version_valid[state["model_id"]].sum(-1).astype(jnp.int32)
     j = jax.random.randint(k1, (n,), 0, nv)
@@ -66,7 +66,7 @@ def greedy_oracle(cfg: EnvConfig, tables: ProfileTables, state, rng=None):
     """Per-step per-UAV reward argmax over all (j, k) — and over the
     server axis too in cluster mode. Canonical registry name:
     ``greedy_oracle`` (repro.policies)."""
-    n = cfg.n_uavs
+    n = state["model_id"].shape[0]
     V, K = tables.n_versions, tables.n_cuts
     S = 1 if cfg.cluster is None else cfg.cluster.n_servers
     w = cfg.weights

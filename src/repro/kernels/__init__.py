@@ -7,8 +7,9 @@
   rglru_scan      — RG-LRU diagonal linear recurrence
   quant_matmul    — int8 x int8 -> int32 matmul with f32 rescale (repro.quant)
 
-Set REPRO_USE_PALLAS=interpret (CPU validation) or =tpu (hardware) to route
-the models through the kernels; unset -> pure-jnp reference path.
+On a TPU backend the models run the compiled kernels (ops.use_pallas);
+elsewhere they take the pure-jnp reference path, and REPRO_USE_PALLAS=interpret
+routes them through the kernels in interpreter mode for the CPU tests.
 """
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode

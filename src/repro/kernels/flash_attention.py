@@ -76,7 +76,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     logit_scale: Optional[float] = None,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q: (B, H, Sq, D); k, v: (B, HK, Skv, D). Returns (B, H, Sq, Dv)."""
     B, H, Sq, D = q.shape
     _, HK, Skv, Dv = v.shape

@@ -9,6 +9,8 @@ stream through VMEM.
 
 Layout: u, dt: (B, S, DI); Bm, Cm: (B, S, N); A: (DI, N).
 grid = (B, DI/bd, S/bc); innermost chunk dim is sequential and carries h.
+A width or length that is not a multiple of its block is padded with
+dt = u = 0 steps (dA = 1, no input), which leave the state unchanged.
 Oracle: models/ssm.py ssm_scan_chunked (minus the D-skip, composed in ops).
 """
 from __future__ import annotations
@@ -52,7 +54,7 @@ def _mamba_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref,
 
 @functools.partial(jax.jit, static_argnames=("bd", "bc", "interpret"))
 def mamba_scan(u, dt, Bm, Cm, A, *, bd: int = 128, bc: int = 128,
-               interpret: bool = True):
+               interpret: bool = False):
     """Selective scan. u, dt: (B,S,DI); Bm, Cm: (B,S,N); A: (DI,N).
 
     Returns (y (B,S,DI), h_final (B,DI,N)). No D-skip/gating (see ops.py).
@@ -61,9 +63,14 @@ def mamba_scan(u, dt, Bm, Cm, A, *, bd: int = 128, bc: int = 128,
     N = Bm.shape[-1]
     bd = min(bd, DI)
     bc = min(bc, S)
-    assert DI % bd == 0, (DI, bd)
-    assert S % bc == 0, (S, bc)
-    nd, nc = DI // bd, S // bc
+    ps, pd = (-S) % bc, (-DI) % bd
+    if ps or pd:
+        seq = ((0, 0), (0, ps), (0, pd))
+        u, dt = jnp.pad(u, seq), jnp.pad(dt, seq)
+        Bm = jnp.pad(Bm, ((0, 0), (0, ps), (0, 0)))
+        Cm = jnp.pad(Cm, ((0, 0), (0, ps), (0, 0)))
+        A = jnp.pad(A, ((0, pd), (0, 0)))
+    nd, nc = (DI + pd) // bd, (S + ps) // bc
 
     kernel = functools.partial(_mamba_kernel, bc=bc, nc=nc)
     y, h = pl.pallas_call(
@@ -81,10 +88,10 @@ def mamba_scan(u, dt, Bm, Cm, A, *, bd: int = 128, bc: int = 128,
             pl.BlockSpec((1, bd, N), lambda b, d, c: (b, d, 0)),    # h_final
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, DI), u.dtype),
-            jax.ShapeDtypeStruct((B, DI, N), jnp.float32),
+            jax.ShapeDtypeStruct(u.shape, u.dtype),
+            jax.ShapeDtypeStruct((B, DI + pd, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
         interpret=interpret,
     )(u, dt, Bm, Cm, A)
-    return y, h
+    return y[:, :S, :DI], h[:, :DI]

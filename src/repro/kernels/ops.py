@@ -1,15 +1,24 @@
 """jit'd wrappers dispatching between Pallas kernels and jnp references.
 
-``use_pallas()`` reads REPRO_USE_PALLAS: "interpret" (CPU validation),
-"tpu" (real lowering on hardware), or unset/0 (pure-jnp path — default in
-this CPU container; the models call these wrappers so flipping one env var
-moves the whole stack onto the kernels).
+The kernel path is chosen by platform (``use_pallas()``): on a TPU backend
+every dispatch runs the compiled Pallas kernel. Elsewhere the models take
+the pure-jnp reference path, unless REPRO_USE_PALLAS=interpret routes them
+through the kernels in interpreter mode (the CPU tests' switch).
+``jnp_reference()`` forces the reference path on any backend, so the
+kernel path can be checked against it on the chip.
+
+The choice is made while a function is traced, and jit caches do not key
+on it: trace a fresh ``jax.jit`` under ``jnp_reference()``.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
+import re
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention as _flash
@@ -22,11 +31,39 @@ from repro.kernels import ref
 from repro.quant.quantize import QTensor, quantize_act
 
 
+_FORCE_REFERENCE = contextvars.ContextVar("force_reference", default=False)
+
+
+@contextlib.contextmanager
+def jnp_reference():
+    """Trace the models on the jnp reference path, whatever the backend."""
+    token = _FORCE_REFERENCE.set(True)
+    try:
+        yield
+    finally:
+        _FORCE_REFERENCE.reset(token)
+
+
 def use_pallas() -> Optional[str]:
-    v = os.environ.get("REPRO_USE_PALLAS", "").lower()
-    if v in ("interpret", "tpu"):
-        return v
+    """"tpu" (compiled kernels), "interpret" (CPU tests) or None (jnp)."""
+    if _FORCE_REFERENCE.get():
+        return None
+    if jax.default_backend() == "tpu":
+        return "tpu"
+    if os.environ.get("REPRO_USE_PALLAS", "").lower() == "interpret":
+        return "interpret"
     return None
+
+
+_TPU_CUSTOM_CALL = re.compile(
+    r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"')
+
+
+def compiled_kernels(compiled) -> set:
+    """Names of the Pallas kernels in a compiled TPU program (each
+    kernel's instruction is named after its jitted wrapper)."""
+    return {m.rsplit(".", 1)[0]
+            for m in _TPU_CUSTOM_CALL.findall(compiled.as_text())}
 
 
 def quantized_dense(x, w: QTensor):
@@ -34,8 +71,8 @@ def quantized_dense(x, w: QTensor):
 
     Weight-only leaves (w8 / packed w4) dequantize to f32 and use the
     plain matmul; w8a8 leaves quantize the activations per row and run the
-    int8 x int8 -> int32 path — the Pallas kernel when REPRO_USE_PALLAS is
-    set, the jnp oracle otherwise. models/layers.py::dense routes every
+    int8 x int8 -> int32 path — the Pallas kernel where ``use_pallas()``
+    says so, the jnp oracle otherwise. models/layers.py::dense routes every
     dense projection here, so a quantized param tree changes no model code.
     """
     if w.act_bits == 8 and w.bits == 8:

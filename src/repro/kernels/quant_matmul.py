@@ -52,7 +52,7 @@ def quant_matmul_ref(x_q, w_q, x_scale, w_scale):
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def quant_matmul(x_q, w_q, x_scale, w_scale, *, bm: int = 128, bn: int = 128,
-                 bk: int = 128, interpret: bool = True):
+                 bk: int = 128, interpret: bool = False):
     """Pallas int8 matmul. Same contract as ``quant_matmul_ref``."""
     M, K = x_q.shape
     K2, N = w_q.shape

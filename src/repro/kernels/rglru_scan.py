@@ -5,7 +5,9 @@ h_t = a_t * h_{t-1} + gx_t, with a/gx precomputed by cheap jnp projections
 memory-bound sequential hot loop, keeping the (bw,) state in VMEM scratch
 across the sequential chunk grid dim.
 
-Layout: a, gx: (B, S, W). grid = (B, W/bw, S/bc).
+Layout: a, gx: (B, S, W). grid = (B, W/bw, S/bc). A width or length that
+is not a multiple of its block is padded with identity steps (a=1, gx=0),
+which leave the state, and so h_last, unchanged.
 Oracle: kernels/ref.py rglru_scan_ref (associative_scan).
 """
 from __future__ import annotations
@@ -36,18 +38,21 @@ def _rglru_kernel(a_ref, gx_ref, y_ref, hout_ref, h_scr, *, bc: int, nc: int):
 
     @pl.when(ic == nc - 1)
     def _finalize():
-        hout_ref[0] = h.astype(hout_ref.dtype)
+        hout_ref[0, 0] = h.astype(hout_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "bc", "interpret"))
 def rglru_scan(a, gx, *, bw: int = 256, bc: int = 128,
-               interpret: bool = True):
+               interpret: bool = False):
     """a, gx: (B, S, W) -> (h_seq (B,S,W), h_last (B,W))."""
     B, S, W = a.shape
     bw = min(bw, W)
     bc = min(bc, S)
-    assert W % bw == 0 and S % bc == 0, (W, bw, S, bc)
-    nw, nc = W // bw, S // bc
+    ps, pw = (-S) % bc, (-W) % bw
+    if ps or pw:
+        a = jnp.pad(a, ((0, 0), (0, ps), (0, pw)), constant_values=1)
+        gx = jnp.pad(gx, ((0, 0), (0, ps), (0, pw)))
+    nw, nc = (W + pw) // bw, (S + ps) // bc
 
     kernel = functools.partial(_rglru_kernel, bc=bc, nc=nc)
     y, h = pl.pallas_call(
@@ -59,13 +64,13 @@ def rglru_scan(a, gx, *, bw: int = 256, bc: int = 128,
         ],
         out_specs=[
             pl.BlockSpec((1, bc, bw), lambda b, w, c: (b, c, w)),
-            pl.BlockSpec((1, bw), lambda b, w, c: (b, w)),
+            pl.BlockSpec((1, 1, bw), lambda b, w, c: (b, 0, w)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, W), a.dtype),
-            jax.ShapeDtypeStruct((B, W), jnp.float32),
+            jax.ShapeDtypeStruct(a.shape, a.dtype),
+            jax.ShapeDtypeStruct((B, 1, W + pw), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
         interpret=interpret,
     )(a, gx)
-    return y, h
+    return y[:, :S, :W], h[:, 0, :W]

@@ -138,11 +138,10 @@ def chunked_attention_causal_skip(q, k, v, *, q_positions, kv_positions,
 def attention(q, k, v, *, q_positions, kv_positions, causal=True, window=None,
               logit_scale=None, chunked_threshold=2048,
               q_chunk=512, kv_chunk=1024, causal_skip=False):
-    """Dispatch: Pallas flash kernel (REPRO_USE_PALLAS), else chunked for
-    long sequences, else plain."""
+    """Dispatch: Pallas flash kernel (``kops.use_pallas()``), else chunked
+    for long sequences, else plain."""
     from repro.kernels import ops as kops
-    if (kops.use_pallas() and q.shape[1] == k.shape[1]
-            and q.shape[1] % 8 == 0):
+    if kops.use_pallas() and q.shape[1] == k.shape[1]:
         out = kops.attention_bhsd(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=causal, window=window,

@@ -14,8 +14,8 @@ from repro.quant.quantize import QTensor
 def dense(x, w):
     """``x @ w`` where w may be a quantized ``QTensor`` leaf.
 
-    The quantized path dispatches through kernels/ops.py (REPRO_USE_PALLAS
-    selects the Pallas int8 kernel); the import is deferred because
+    The quantized path dispatches through kernels/ops.py (which runs the
+    Pallas int8 kernel on a TPU backend); the import is deferred because
     kernels -> ref -> ssm imports this module at package-init time.
     """
     if isinstance(w, QTensor):

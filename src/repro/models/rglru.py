@@ -98,7 +98,7 @@ def apply_rec(cfg: ModelConfig, p, x, *, mode: str, cache=None):
     else:
         from repro.kernels import ops as kops
         uc = causal_conv1d(u, p["conv_w"], p["conv_b"])
-        if kops.use_pallas() and S % 128 == 0 and w % 128 == 0:
+        if kops.use_pallas():
             a, gx = _gates(p, uc)
             y32, h_last = kops.rglru_scan_full(a, gx)
             y = y32.astype(x.dtype)

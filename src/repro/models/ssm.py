@@ -118,7 +118,7 @@ def apply_ssm(cfg: ModelConfig, p, x, *, mode: str, cache=None):
     else:
         from repro.kernels import ops as kops
         u = jax.nn.silu(causal_conv1d(xin, p["conv_w"], p["conv_b"]))
-        if kops.use_pallas() and S % 128 == 0 and di % 128 == 0:
+        if kops.use_pallas():
             dt, Bm, Cm = _ssm_params(cfg, p, u)
             y, h = kops.mamba_scan_full(cfg, p, u, dt, Bm, Cm)
         else:

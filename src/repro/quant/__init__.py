@@ -9,7 +9,7 @@ real variants; ``build_version_params`` materializes the per-version param
 trees the SplitServingEngine executes. The int8 matmul itself lives in
 kernels/quant_matmul.py; models route every dense projection through
 models/layers.py::dense, which hands QTensor leaves to
-kernels/ops.py::quantized_dense (the REPRO_USE_PALLAS dispatch point).
+kernels/ops.py::quantized_dense (the kernel dispatch point).
 """
 from repro.quant.quantize import (DENSE_WEIGHTS, QTensor, dequantize_tree,
                                   quantize, quantize_act, quantize_tree,

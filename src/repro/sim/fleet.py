@@ -104,6 +104,9 @@ class SimResult:
     # flight recorder (FleetConfig.timeline=True): repro.obs.timeline
     # Timeline with per-epoch series, annotations and the SLO report
     timeline: object = None
+    # scan engine: how many jax devices hold the result (the mesh size
+    # under shard=True, 1 otherwise)
+    mesh_devices: int = 1
 
     @property
     def modal_selection(self):

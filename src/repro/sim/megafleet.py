@@ -390,11 +390,10 @@ def simulate_scan(env_cfg: EnvConfig, tables: ProfileTables, policy,
 
     with obs.span("fleet.scan", epochs=T, devices=n, shard=fleet.shard):
         if fleet.shard:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import Mesh
             from jax.sharding import PartitionSpec as P
             mesh = Mesh(np.asarray(jax.devices()), ("d",))
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 lambda c, e, m, b, w, p: run(
                     c, e, m, b, w, p, jax.lax.axis_index("d")),
                 mesh=mesh,
@@ -402,11 +401,12 @@ def simulate_scan(env_cfg: EnvConfig, tables: ProfileTables, policy,
                           P("d")),
                 out_specs=(P(), (P(),) * 9),
                 # accumulators are psum'd every epoch (replicated by
-                # construction); skip the conservative rep checker
-                check_rep=False)
+                # construction); skip the conservative replication check
+                check_vma=False)
             acc, ys = jax.jit(sharded)(*args)
         else:
             acc, ys = jax.jit(run, static_argnums=(6,))(*args, 0)
+        mesh_devices = len(acc["count"].sharding.device_set)
         acc = jax.tree.map(np.asarray, acc)
         ys = jax.tree.map(np.asarray, ys)
 
@@ -461,4 +461,5 @@ def simulate_scan(env_cfg: EnvConfig, tables: ProfileTables, policy,
     return SimResult(summary=summary, metrics=metrics,
                      selection_hist=sel_hist, epochs=T, served=served,
                      duration_s=duration, cross_check=None,
-                     epoch_log=epoch_log, adaptation=None, timeline=tl)
+                     epoch_log=epoch_log, adaptation=None, timeline=tl,
+                     mesh_devices=mesh_devices)

@@ -41,7 +41,8 @@ def test_flash_attention_sweep(B, H, HK, Sq, Skv, D, dtype, causal, window):
 
 
 @pytest.mark.parametrize("B,S,DI,N", [
-    (1, 128, 128, 8), (2, 256, 256, 16), (1, 384, 128, 4)])
+    (1, 128, 128, 8), (2, 256, 256, 16), (1, 384, 128, 4),
+    (2, 200, 200, 8)])            # padded length and width
 @pytest.mark.parametrize("dtype", [jnp.float32])
 def test_mamba_scan_sweep(B, S, DI, N, dtype):
     r = np.random.default_rng(1)
@@ -70,7 +71,8 @@ def test_mamba_scan_sweep(B, S, DI, N, dtype):
 
 
 @pytest.mark.parametrize("B,S,W", [(1, 128, 256), (2, 256, 512),
-                                   (1, 384, 128)])
+                                   (1, 384, 128),
+                                   (2, 200, 300)])   # padded length and width
 def test_rglru_scan_sweep(B, S, W):
     r = np.random.default_rng(2)
     a = jnp.asarray(r.uniform(0.7, 0.999, size=(B, S, W)), jnp.float32)

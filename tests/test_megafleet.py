@@ -151,6 +151,25 @@ def test_scan_shard_matches_unsharded():
     assert np.array_equal(a.selection_hist, b.selection_hist)
 
 
+def test_scan_shard_over_four_virtual_devices():
+    """shard=True over a 4-device mesh (virtual CPU devices, so in a
+    child process): exact workload accounting against shard=False."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    env["PYTHONPATH"] = os.path.join(repo, "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    code = ("import chip_smoke; "
+            "chip_smoke.phase_fleet_sharded(4, devices=2002, epochs=3)")
+    out = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "shard=True (mesh of 4)" in out.stdout
+
+
 def test_scan_rejects_unsupported_modes():
     sc, env_cfg, tables, mids, bf = _world("link-brownout")
     pol = build_policy("device_only", env_cfg, tables)

@@ -47,6 +47,21 @@ def test_static_policy_has_no_artifact_lifecycle():
         pol.train()
 
 
+@pytest.mark.parametrize("name", ["device_only", "full_offload", "random",
+                                  "greedy_oracle"])
+def test_static_policy_acts_on_a_fleet_shard(name):
+    """A static policy sizes its actions from the state it is given, so
+    the sharded scan engine can run it on one shard of the fleet."""
+    from repro.core.controller import measured_state
+    env_cfg, tables = make_paper_env(n_uavs=8)
+    n = 3
+    state = measured_state(env_cfg, tables, battery_j=np.full(n, 1e4),
+                           bandwidth=np.full(n, 1e7), p_tx=np.full(n, 1.0),
+                           queue_jobs=0.0, load=np.full(n, 0.5))
+    pol = build_policy(name, env_cfg, tables)
+    assert pol.act(state, jax.random.key(0)).shape == (n, 2)
+
+
 def test_untrained_policy_refuses_to_act():
     cfg, tables = make_paper_env()
     pol = build_policy("a2c", cfg, tables, episodes=1)
