@@ -22,13 +22,13 @@ from repro.obs.events import (SCHEMA_VERSION, NullRecorder, Recorder,
                               debug, event, get_recorder, get_verbosity,
                               info, log, read_events, recording,
                               set_recorder, set_verbosity, span, warn)
-from repro.obs.metrics import Metrics, gauge, inc, observe
+from repro.obs.metrics import Metrics, inc, observe
 
 __all__ = [
     "SCHEMA_VERSION", "Recorder", "NullRecorder", "Metrics",
     "span", "event", "recording", "get_recorder", "set_recorder",
     "read_events",
-    "inc", "gauge", "observe",
+    "inc", "observe",
     "log", "info", "debug", "warn", "set_verbosity", "get_verbosity",
     "jaxmon", "report",
     # flight recorder (lazy imports below: timeline/slo/traindiag pull

@@ -6,14 +6,20 @@ Design rules (DESIGN.md §9):
 
 - **Null by default, zero overhead off.** The module-global recorder is
   a ``NullRecorder`` whose ``span()`` returns one shared no-op context
-  manager — a disabled ``with obs.span(...)`` is a dict-free attribute
-  lookup plus two no-op calls, unmeasurable against the fleet loop's
-  per-epoch work (acceptance: ``fleet_sim`` within 2% with recording
-  off).
+  manager — a disabled ``with obs.span(...)`` is the profiler check
+  below, an attribute lookup and two no-op calls, unmeasurable against
+  the fleet loop's per-epoch work (acceptance: ``fleet_sim`` within 2%
+  with recording off).
 - **Recording never changes results.** Spans and events read the
   monotonic clock and append dicts; they consume no RNG and touch no
   simulation state, so ``SimResult``/``ComparisonReport`` are
   bit-identical with recording on vs. off (tested).
+- **Mirrored into the profiler.** While a ``jax.profiler`` session is
+  active, every ``obs.span(name, **attrs)`` is also a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` with its attrs
+  as arguments, on the device trace's clock, whatever the recorder. Off,
+  the mirror costs one ``TraceAnnotation.is_enabled()`` check; before
+  jax is imported, or without jax, it costs nothing more.
 - **No host callbacks on traced paths.** Nothing here may be called
   from *inside* a jitted computation (no ``io_callback``/``debug``
   hooks): spans wrap host-side calls around jit boundaries, and the
@@ -65,9 +71,6 @@ class _NullMetrics:
     __slots__ = ()
 
     def inc(self, name, value=1.0, **labels):
-        return None
-
-    def gauge(self, name, value, **labels):
         return None
 
     def observe(self, name, value, **labels):
@@ -265,8 +268,62 @@ def set_recorder(rec) -> None:
     _RECORDER = rec if rec is not None else _NULL
 
 
+# --------------------------------------------------------------------------
+# profiler mirror
+# --------------------------------------------------------------------------
+
+PROFILER_PREFIX = "repro."
+
+
+class _Mirror:
+    """A recorder span inside a profiler ``TraceAnnotation`` of the same
+    name (prefixed) and attrs."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, span, name: str, attrs: Dict):
+        self._ann = _Annotation(PROFILER_PREFIX + name, **attrs)
+        self._span = span
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return self._span.__exit__(*exc)
+        finally:
+            self._ann.__exit__(*exc)
+
+
+def _never() -> bool:
+    return False
+
+
+def _resolve_profiling() -> bool:
+    """First check once jax is imported: bind ``_profiling`` to
+    ``TraceAnnotation.is_enabled`` (or to ``_never`` without a usable
+    jax). Before jax is imported no profiler session can be active."""
+    global _profiling, _Annotation
+    if "jax" not in sys.modules:
+        return False
+    try:
+        from jax.profiler import TraceAnnotation
+        _Annotation, _profiling = TraceAnnotation, TraceAnnotation.is_enabled
+    except (ImportError, AttributeError):
+        _profiling = _never
+    return _profiling()
+
+
+_profiling = _resolve_profiling
+_Annotation = None
+
+
 def span(name: str, /, **attrs):
-    """Nested timed region on the active recorder (no-op when off)."""
+    """Nested timed region on the active recorder (no-op when off), also
+    a ``repro.<name>`` TraceAnnotation while a profiler session is on."""
+    if _profiling():
+        return _Mirror(_RECORDER.span(name, **attrs), name, attrs)
     return _RECORDER.span(name, **attrs)
 
 

@@ -1,7 +1,7 @@
-"""Counters, gauges and histograms with labeled series.
+"""Counters and histograms with labeled series.
 
 A ``Metrics`` registry lives on each ``Recorder``; the module-level
-``inc``/``gauge``/``observe`` helpers dispatch through the process
+``inc``/``observe`` helpers dispatch through the process
 recorder (no-ops when recording is off). Each (name, labels) pair is
 one series — e.g. ``inc("fleet.dropped", 3, policy="a2c")`` and
 ``inc("fleet.dropped", 1, policy="ppo")`` accumulate independently —
@@ -26,19 +26,15 @@ def _key(name: str, labels: Dict) -> Tuple:
 
 
 class Metrics:
-    """Label-keyed counter/gauge/histogram registry (one per Recorder)."""
+    """Label-keyed counter/histogram registry (one per Recorder)."""
 
     def __init__(self):
         self._counters: Dict[Tuple, float] = {}
-        self._gauges: Dict[Tuple, float] = {}
         self._hists: Dict[Tuple, List[float]] = {}
 
     def inc(self, name: str, value: float = 1.0, **labels):
         k = _key(name, labels)
         self._counters[k] = self._counters.get(k, 0.0) + float(value)
-
-    def gauge(self, name: str, value: float, **labels):
-        self._gauges[_key(name, labels)] = float(value)
 
     def observe(self, name: str, value: float, **labels):
         self._hists.setdefault(_key(name, labels), []).append(float(value))
@@ -48,9 +44,6 @@ class Metrics:
         out = []
         for (name, labels), v in sorted(self._counters.items()):
             out.append({"type": "metric", "kind": "counter", "name": name,
-                        "labels": dict(labels), "value": v})
-        for (name, labels), v in sorted(self._gauges.items()):
-            out.append({"type": "metric", "kind": "gauge", "name": name,
                         "labels": dict(labels), "value": v})
         for (name, labels), vals in sorted(self._hists.items()):
             a = np.asarray(vals)
@@ -68,10 +61,6 @@ class Metrics:
 
 def inc(name: str, value: float = 1.0, **labels) -> None:
     _ev.get_recorder().metrics.inc(name, value, **labels)
-
-
-def gauge(name: str, value: float, **labels) -> None:
-    _ev.get_recorder().metrics.gauge(name, value, **labels)
 
 
 def observe(name: str, value: float, **labels) -> None:

@@ -9,6 +9,11 @@ decision (version j, cut l) routes each request batch — the head segment
 runs as one jit (the "UAV"/head submesh), the cut activation crosses the
 link, the tail + decode runs as another jit (the edge-server submesh).
 The two jits exercise exactly the partition the paper's Fig. 1 shows.
+They are named ``split_head`` and ``split_tail``, so a device trace shows
+them as ``jit_split_head(…)`` and ``jit_split_tail(…)``; ``infer`` is
+spanned as ``split.infer`` ⊃ {``split.head``, ``split.link``,
+``split.tail``} (``repro.split.*`` in a profiler trace) and counts the
+wire bytes as ``split.link_bytes``.
 """
 from __future__ import annotations
 
@@ -18,9 +23,11 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core import partition
 from repro.models import model as M
+from repro.obs import jaxmon
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,10 +117,17 @@ class SplitServingEngine:
         key = (cut, version)
         if key not in self._heads:
             cfg = self.cfg
-            self._heads[key] = jax.jit(
-                lambda p, b: partition.run_head(cfg, p, b, cut))
-            self._tails[key] = jax.jit(
-                lambda p, a, b: partition.run_tail(cfg, p, a, b, cut))
+
+            def split_head(p, b):
+                jaxmon.count_trace("split.head")
+                return partition.run_head(cfg, p, b, cut)
+
+            def split_tail(p, a, b):
+                jaxmon.count_trace("split.tail")
+                return partition.run_tail(cfg, p, a, b, cut)
+
+            self._heads[key] = jax.jit(split_head)
+            self._tails[key] = jax.jit(split_tail)
         return self._heads[key], self._tails[key]
 
     def infer(self, batch: Dict, cut: Tuple[str, int],
@@ -123,17 +137,23 @@ class SplitServingEngine:
         into the EdgeRL env's cut_bytes axis."""
         from repro.quant import get_version, quantize_act
 
-        params = self._params_for(version)
-        head, tail = self._fns(cut, version)
-        act = head(params, batch)
-        if get_version(version).act_bits == 8:
-            # the link carries int8 codes + per-row scales, like the
-            # w8a8 matmuls inside the trunk
-            q, s = quantize_act(act)
-            act_bytes = (q.size * q.dtype.itemsize
-                         + s.size * s.dtype.itemsize)
-            act = (q.astype(jnp.float32) * s).astype(act.dtype)
-        else:
-            act_bytes = act.size * act.dtype.itemsize
-        logits = tail(params, act, batch)
+        with obs.span("split.infer", version=version, cut=cut,
+                      S=batch["tokens"].shape[1]):
+            with obs.span("split.head"):
+                params = self._params_for(version)
+                head, tail = self._fns(cut, version)
+                act = head(params, batch)
+            with obs.span("split.link"):
+                if get_version(version).act_bits == 8:
+                    # the link carries int8 codes + per-row scales, like
+                    # the w8a8 matmuls inside the trunk
+                    q, s = quantize_act(act)
+                    act_bytes = (q.size * q.dtype.itemsize
+                                 + s.size * s.dtype.itemsize)
+                    act = (q.astype(jnp.float32) * s).astype(act.dtype)
+                else:
+                    act_bytes = act.size * act.dtype.itemsize
+            obs.inc("split.link_bytes", act_bytes, version=version)
+            with obs.span("split.tail"):
+                logits = tail(params, act, batch)
         return logits, act_bytes

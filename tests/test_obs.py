@@ -35,7 +35,7 @@ def test_null_recorder_is_default_and_noop():
     with s1:
         pass
     obs.event("nothing", y=2)                      # no-op, no error
-    obs.inc("c"), obs.gauge("g", 1.0), obs.observe("h", 2.0)
+    obs.inc("c"), obs.observe("h", 2.0)
 
 
 def test_recording_installs_and_restores(tmp_path):
@@ -116,15 +116,12 @@ def test_metrics_counters_gauges_histograms():
     m.inc("req", 2.0, policy="a2c")
     m.inc("req", 3.0, policy="a2c")
     m.inc("req", 1.0, policy="greedy")
-    m.gauge("level", 0.5)
-    m.gauge("level", 0.7)                    # last write wins
     for v in range(1, 101):
         m.observe("lat", float(v))
     snap = {(s["name"], tuple(sorted(s.get("labels", {}).items()))): s
             for s in m.snapshot()}
     assert snap[("req", (("policy", "a2c"),))]["value"] == 5.0
     assert snap[("req", (("policy", "greedy"),))]["value"] == 1.0
-    assert snap[("level", ())]["value"] == 0.7
     h = snap[("lat", ())]
     assert h["kind"] == "histogram" and h["count"] == 100
     assert h["min"] == 1.0 and h["max"] == 100.0
