@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention, flash_plan
 from repro.kernels.mamba_scan import mamba_scan
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels import ref
@@ -20,10 +20,15 @@ TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
     (1, 8, 1, 256, 256, 128),
     (2, 4, 4, 200, 200, 64),      # non-multiple of block
     (1, 2, 1, 64, 320, 64),       # cross-length (non-causal)
+    (1, 14, 2, 256, 256, 64),     # qwen2's grouping: G = 7 heads per kv head
+    (1, 14, 2, 200, 200, 64),     # ... padded to a block
+    (1, 16, 8, 384, 384, 128),    # qwen3's grouping: G = 2, D = 128
+    (1, 2, 1, 2048, 2048, 64),    # four kv blocks: dead blocks on both sides
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+# window 96 is narrower than a block; 700 spans two of the 512-key blocks
 @pytest.mark.parametrize("causal,window", [(True, None), (True, 96),
-                                           (False, None)])
+                                           (False, None), (True, 700)])
 def test_flash_attention_sweep(B, H, HK, Sq, Skv, D, dtype, causal, window):
     if not causal and Sq != Skv:
         pass  # cross-attention-like case still valid
@@ -38,6 +43,64 @@ def test_flash_attention_sweep(B, H, HK, Sq, Skv, D, dtype, causal, window):
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
+
+
+def _live_pairs(plan, Sq_pad, Skv_pad):
+    """(q block, kv block) pairs holding a position that may attend,
+    found by brute force over every position."""
+    q = np.arange(Sq_pad)[:, None]
+    k = np.arange(Skv_pad)[None, :]
+    ok = np.ones((Sq_pad, Skv_pad), bool)
+    if plan.causal:
+        ok &= k <= q
+    if plan.window is not None:
+        ok &= (q - k) < plan.window
+    _, _, nq, nk = plan.grid
+    return ok.reshape(nq, plan.bq, nk, plan.bk).any(axis=(1, 3))
+
+
+@pytest.mark.parametrize("B,H,HK,Sq,D,causal,window", [
+    (1, 14, 2, 1024, 64, True, None),
+    (1, 14, 2, 256, 64, True, None),
+    (2, 4, 2, 200, 64, True, 96),
+    (1, 2, 1, 2048, 64, True, 700),
+    (1, 16, 8, 384, 128, True, None),
+    (1, 14, 2, 1024, 64, False, None),
+])
+def test_flash_plan(B, H, HK, Sq, D, causal, window):
+    plan = flash_plan(B, H, HK, Sq, Sq, D, causal, window)
+    _, _, nq, nk = plan.grid
+    assert plan.grid[:2] == (B, HK)
+    live = _live_pairs(plan, nq * plan.bq, nk * plan.bk)
+    assert plan.live_steps == B * HK * int(live.sum())
+    if not causal and window is None:
+        assert plan.live_steps == plan.total_steps
+    # steps run in grid order, kv innermost: a dead step names the block
+    # the last live step read, or, before a q block's first live step,
+    # the block that step will read; either way no new DMA
+    lo, hi = plan.kv_range(np.arange(nq), np)
+    for i in range(nq):
+        for j in range(nk):
+            got = int(plan.kv_block(i, j, np))
+            if live[i, j]:
+                assert lo[i] <= j <= hi[i] and got == j
+            elif j > hi[i]:
+                assert got == hi[i]
+            else:
+                assert got == lo[i]
+
+
+def test_flash_plan_qwen2_steps():
+    # qwen2-0.5b: 14 query heads over 2 kv heads (G = 7), D = 64.
+    # S = 1024: bq = 128, bk = 512, so 8 q blocks by 2 kv blocks per kv
+    # head. q blocks 0-3 (queries 0-511) see only kv block 0; q blocks
+    # 4-7 see both: 4 * 1 + 4 * 2 = 12 live of 16, times 2 kv heads.
+    plan = flash_plan(1, 14, 2, 1024, 1024, 64, True, None)
+    assert (plan.bq, plan.bk, plan.grid) == (128, 512, (1, 2, 8, 2))
+    assert (plan.live_steps, plan.total_steps) == (24, 32)
+    # S = 256: one 256-key block, 2 q blocks, both live, per kv head
+    plan = flash_plan(1, 14, 2, 256, 256, 64, True, None)
+    assert plan.total_steps <= 4 and plan.live_steps == 4
 
 
 @pytest.mark.parametrize("B,S,DI,N", [

@@ -80,6 +80,10 @@ F32, I8, I32 = jnp.float32, jnp.int8, jnp.int32
     # recurrentgemma-2b: lru width 2560, batch 2
     ("rglru_scan", rglru_scan,
      [((2, 300, 2560), F32), ((2, 300, 2560), F32)]),
+    # qwen2-0.5b heads at the benchmark cell's longest prompt (B = 1, 1024)
+    ("flash_attention", flash_attention,
+     [((1, 14, 1024, 64), F32), ((1, 2, 1024, 64), F32),
+      ((1, 2, 1024, 64), F32)]),
 ])
 def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
     compiled = _compile(functools.partial(fn, interpret=False), one_chip,
