@@ -257,7 +257,7 @@ def hillclimb_variants():
     pairs = [("deepseek-v2-lite-16b", "decode_32k",
               ["baseline", "mla_absorb"]),
              ("mixtral-8x22b", "train_4k",
-              ["baseline", "moe_gather", "causal_skip", "noremat", "fsdp"]),
+              ["baseline", "causal_skip", "noremat", "fsdp"]),
              ("llama-3.2-vision-90b", "prefill_32k",
               ["baseline", "hugechunk", "causal_skip"])]
     for arch, shape, variants in pairs:

@@ -1,6 +1,6 @@
 """Architecture configs (assigned pool) + shape suite.
 
-``ALL_ARCHS`` lists the 10 assigned architecture ids; importing this module
+``ALL_ARCHS`` lists the registered architecture ids; importing this module
 registers all of them. ``SHAPES`` is the assigned input-shape suite.
 """
 from repro.configs.base import ModelConfig, get_config, list_configs, register
@@ -17,6 +17,7 @@ from repro.configs import (  # noqa: F401
     qwen3_0_6b,
     whisper_large_v3,
     mixtral_8x22b,
+    mellum2_12b_a2_5b,
 )
 
 ALL_ARCHS = (
@@ -30,6 +31,7 @@ ALL_ARCHS = (
     "qwen3-0.6b",
     "whisper-large-v3",
     "mixtral-8x22b",
+    "mellum2-12b-a2.5b",
 )
 
 # (name, seq_len, global_batch, kind)

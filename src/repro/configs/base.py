@@ -3,9 +3,10 @@
 One ``ModelConfig`` dataclass covers every assigned architecture family:
 dense (GQA), MoE (top-k routed + shared experts, MLA), SSM (Mamba-1),
 hybrid (RG-LRU + local attention), encoder-decoder audio (Whisper) and
-VLM (interleaved cross-attention). Every config file in this package
-instantiates one ``ModelConfig`` with the exact assigned hyper-parameters
-and cites its source in ``source``.
+VLM (interleaved cross-attention), and stacks that mix sliding-window
+and full attention layers (``layer_types``). Every config file in this
+package instantiates one ``ModelConfig`` with the exact assigned
+hyper-parameters and cites its source in ``source``.
 """
 from __future__ import annotations
 
@@ -13,6 +14,19 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeYarn:
+    """YaRN scaling of rotary frequencies (arXiv:2309.00071), with the
+    parameters of a Hugging Face ``rope_type: "yarn"`` entry."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0    # multiplies cos and sin
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,6 +54,10 @@ class ModelConfig:
     qkv_bias: bool = False           # qwen2-style QKV bias
     qk_norm: bool = False            # qwen3-style per-head RMSNorm on q,k
     sliding_window: Optional[int] = None   # SWA window; None = full attention
+    # per-layer attention type (FULL / SLIDING, the published
+    # ``layer_types``); empty: every layer attends alike (sliding_window)
+    layer_types: Tuple[str, ...] = ()
+    rope_yarn: Optional[RopeYarn] = None   # YaRN RoPE of the full layers
     attn_bias: bool = False          # bias on all attn projections (whisper)
 
     # -- MLA (deepseek-v2) ----------------------------------------------------
@@ -57,10 +75,10 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0                # per-expert hidden dim
     first_dense_layers: int = 0      # deepseek: leading dense layers
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
-    moe_impl: str = "einsum"         # einsum (GShard baseline) | gather (opt)
-    moe_chunk: int = 1024            # dispatch chunk (perf knob)
+    # experts this chip holds, 0 .. experts_held - 1 of n_experts (expert
+    # parallelism); 0 = all of them
+    experts_held: int = 0
 
     # -- SSM (mamba-1) ----------------------------------------------------------
     ssm: bool = False
@@ -115,6 +133,22 @@ class ModelConfig:
     @property
     def resolved_lru_width(self) -> int:
         return self.lru_width if self.lru_width is not None else self.d_model
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def mixed_attention(self) -> bool:
+        """Whether the trunk mixes full and sliding-window layers."""
+        return len(set(self.layer_types[:self.n_layers])) > 1
+
+    def attention_windows(self) -> Tuple[Optional[int], ...]:
+        """Per decoder layer, the attention window (None = full)."""
+        if not self.layer_types:
+            return (self.sliding_window,) * self.n_layers
+        return tuple(self.sliding_window if t == SLIDING else None
+                     for t in self.layer_types[:self.n_layers])
 
     @property
     def d_inner(self) -> int:
@@ -175,15 +209,16 @@ class ModelConfig:
             compute_dtype="float32",
         )
         if self.moe:
+            n_exp = min(self.n_experts, 4)
             kw.update(
-                n_experts=min(self.n_experts, 4),
+                n_experts=n_exp,
                 top_k=min(self.top_k, 2),
                 moe_d_ff=min(self.moe_d_ff, 256),
                 first_dense_layers=min(self.first_dense_layers, 1),
-                # non-dropping capacity: capacity-based routing drops tokens
-                # chunk-dependently, which would make prefill-vs-decode
-                # consistency checks impossible (production keeps 1.25)
-                capacity_factor=float(self.n_experts) / max(self.top_k, 1),
+                # a partial hold keeps its share of the experts
+                experts_held=(max(1, self.experts_held * n_exp
+                                  // self.n_experts)
+                              if self.experts_held else 0),
             )
         if self.use_mla:
             kw.update(kv_lora_rank=64, qk_rope_head_dim=16,
@@ -201,6 +236,12 @@ class ModelConfig:
             kw.update(n_layers=4, cross_attn_every=2, n_media_tokens=8)
         if self.sliding_window is not None:
             kw.update(sliding_window=min(self.sliding_window, 64))
+        if self.mixed_attention:
+            # one whole period of the layer pattern, and a window shorter
+            # than the smoke tests' sequences
+            period = self.layer_types.index(FULL) + 1
+            kw.update(n_layers=period,
+                      sliding_window=min(self.sliding_window, 8))
         return self.with_overrides(**kw)
 
 

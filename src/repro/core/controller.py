@@ -59,13 +59,15 @@ def make_tpu_env(arch_names: Sequence[str],
                  reduced: bool = False,
                  **env_kw) -> Tuple[EnvConfig, ProfileTables]:
     """TPU-adapted env whose version axis is the repro.quant registry
-    (bf16 / w8 / w4 — see DESIGN.md §3). ``reduced=True`` profiles the
-    smoke-test variant of each arch so table indices line up with an
-    executable SplitServingEngine model (used by tests/examples that run
-    the controller's decisions end-to-end)."""
-    from repro.configs import get_config
+    (bf16 / w8 / w4 — see DESIGN.md §3). Each device's model is an arch
+    name or a ModelConfig (a registered arch cut to what one chip serves).
+    ``reduced=True`` profiles the smoke-test variant of each arch so table
+    indices line up with an executable SplitServingEngine model (used by
+    tests/examples that run the controller's decisions end-to-end)."""
+    from repro.configs import ModelConfig, get_config
 
-    cfgs = [get_config(a) for a in arch_names]
+    cfgs = [a if isinstance(a, ModelConfig) else get_config(a)
+            for a in arch_names]
     if reduced:
         cfgs = [c.reduced() for c in cfgs]
     profs = [transformer_profile(c, seq_len=seq_len) for c in cfgs]

@@ -11,6 +11,7 @@ tests/test_partition.py). ``cut_points`` enumerates the legal boundaries.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Tuple
 
 import jax
@@ -56,16 +57,27 @@ def _slice_stack(p_stack, lo, hi):
     return jax.tree.map(lambda a: a[lo:hi], p_stack)
 
 
+def _layer0(cfg, stack_name: str) -> int:
+    """Decoder layer index of the first layer of stack ``stack_name``."""
+    n = 0
+    for s in M.stack_defs(cfg):
+        if s.name == stack_name:
+            return n
+        n += s.layers
+    raise KeyError(stack_name)
+
+
 def _run_stacks(cfg, params, x, segments, kv_src):
     aux = jnp.float32(0.0)
     for (sdef, lo, hi) in segments:
         if hi <= lo:
             continue
         sliced = _slice_stack(params["stacks"][sdef.name], lo, hi)
-        import dataclasses
         sub_def = dataclasses.replace(sdef, length=hi - lo)
+        per = sdef.layers // sdef.length
         x, _, a = M._apply_stack(cfg, sub_def, sliced, x, mode="train",
-                                 pos0=jnp.int32(0), kv_src=kv_src)
+                                 pos0=jnp.int32(0), kv_src=kv_src,
+                                 layer0=_layer0(cfg, sdef.name) + lo * per)
         aux = aux + a
     return x, aux
 

@@ -33,7 +33,10 @@ def _mlp_flops(cfg: ModelConfig, d_ff: int) -> float:
 
 
 def _moe_flops(cfg: ModelConfig) -> float:
-    active = cfg.top_k + cfg.n_shared_experts
+    """Per token: the router over all experts, the shared experts, and
+    this chip's share of the routed ones (top_k x held / n_experts)."""
+    active = cfg.top_k * cfg.resolved_experts_held / cfg.n_experts \
+        + cfg.n_shared_experts
     return 2.0 * 3 * cfg.d_model * cfg.moe_d_ff * active \
         + 2.0 * cfg.d_model * cfg.n_experts          # router
 
@@ -68,8 +71,7 @@ def block_flops_per_token(cfg: ModelConfig, seq_ctx: int = None, *,
     2 gives a per-block *parameter count* that is independent of the
     profiling shape (used for weight-shipping bytes)."""
     ctx = 0 if weights_only else (seq_ctx if seq_ctx is not None else 2048)
-    if cfg.sliding_window:
-        ctx = min(ctx, cfg.sliding_window)
+    windows = cfg.attention_windows()
     enc_ctx = 0 if weights_only else cfg.encoder_seq
     media_ctx = 0 if weights_only else cfg.n_media_tokens
     out = []
@@ -83,11 +85,12 @@ def block_flops_per_token(cfg: ModelConfig, seq_ctx: int = None, *,
                        + _mlp_flops(cfg, cfg.d_ff))
         elif cfg.enc_dec:
             # whisper decoder block: self-attn + cross-attn(enc) + mlp
-            out.append(_attn_flops(cfg, ctx)
+            out.append(_attn_flops(cfg, min(ctx, windows[i] or ctx))
                        + _attn_flops(cfg, enc_ctx)
                        + _mlp_flops(cfg, cfg.d_ff))
         else:
-            lctx = min(ctx, cfg.local_window) if cfg.block_pattern else ctx
+            lctx = min(ctx, cfg.local_window if cfg.block_pattern
+                       else windows[i] or ctx)
             mlp = (_moe_flops(cfg) if (cfg.moe and i >= cfg.first_dense_layers)
                    else _mlp_flops(cfg, cfg.d_ff if cfg.d_ff else 4 * cfg.d_model))
             out.append(_attn_flops(cfg, lctx) + mlp)
@@ -98,12 +101,14 @@ def block_params(cfg: ModelConfig) -> List[float]:
     """Per-block parameter-count estimate: weight-matmul FLOPs / 2 with
     all attention contexts zeroed (shape-independent, unlike raw FLOPs).
 
-    MoE layers are corrected to count ALL experts — FLOPs only touch the
-    routed top-k, but shipping/storing a layer moves every expert."""
+    MoE layers are corrected to count every expert this chip holds —
+    FLOPs only touch the routed share, but shipping/storing a layer moves
+    every held expert."""
     out = [f / 2.0 for f in block_flops_per_token(cfg, weights_only=True)]
     if cfg.moe:
+        held = cfg.resolved_experts_held
         inactive = 3.0 * cfg.d_model * cfg.moe_d_ff \
-            * (cfg.n_experts - cfg.top_k)
+            * (held - cfg.top_k * held / cfg.n_experts)
         for i, kind in enumerate(cfg.layer_kinds()):
             if kind == "attn" and i >= cfg.first_dense_layers:
                 out[i] += inactive

@@ -17,7 +17,8 @@ m and l replicated across the 128 lanes.
 (q block, kv block) pairs: a pair is dead when causal masking or the
 sliding window masks all of it. A dead step computes nothing, and its
 k/v index map names a block already in VMEM, so it issues no DMA.
-Causal + sliding-window masking is positional.
+Causal + sliding-window masking is positional. Calls with a window are
+the jitted ``flash_attention_window``, so a device trace names them apart.
 
 TPU is the TARGET; correctness is validated on CPU with interpret=True
 against kernels/ref.py (pure jnp oracle).
@@ -169,7 +170,28 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     logit_scale: Optional[float] = None,
                     interpret: bool = False):
-    """q: (B, H, Sq, D); k, v: (B, HK, Skv, D). Returns (B, H, Sq, Dv)."""
+    """q: (B, H, Sq, D); k, v: (B, HK, Skv, D). Returns (B, H, Sq, Dv).
+    A call with a sliding window runs as ``flash_attention_window``."""
+    if window is not None:
+        return flash_attention_window(q, k, v, window=window, causal=causal,
+                                      logit_scale=logit_scale,
+                                      interpret=interpret)
+    return _flash(q, k, v, causal, None, logit_scale, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "causal",
+                                             "interpret", "logit_scale"))
+def flash_attention_window(q, k, v, *, window: int, causal: bool = True,
+                           logit_scale: Optional[float] = None,
+                           interpret: bool = False):
+    """``flash_attention`` with a sliding window, under its own name in a
+    device trace: its work is the window's band of keys, not the causal
+    triangle, so its costs are counted apart."""
+    return _flash(q, k, v, causal, window, logit_scale, interpret)
+
+
+def _flash(q, k, v, causal: bool, window: Optional[int],
+           logit_scale: Optional[float], interpret: bool):
     B, H, Sq, D = q.shape
     _, HK, Skv, Dv = v.shape
     assert H % HK == 0

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.flash_decode import flash_decode as _flash_decode
+from repro.kernels.grouped_matmul import grouped_matmul as _grouped_matmul
 from repro.kernels.mamba_scan import mamba_scan as _mamba_scan
 from repro.kernels.quant_matmul import quant_matmul as _quant_matmul
 from repro.kernels.quant_matmul import quant_matmul_ref as _quant_matmul_ref
@@ -100,6 +101,16 @@ def attention_bhsd(q, k, v, *, causal=True, window=None, logit_scale=None):
                       interpret=(mode == "interpret"))
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                    logit_scale=logit_scale)
+
+
+def grouped_matmul(lhs, rhs, starts):
+    """Rows laid out by group starts times their group's matrix (the
+    expert FFNs of models/moe.py) via the Pallas kernel or its oracle."""
+    mode = use_pallas()
+    if mode:
+        return _grouped_matmul(lhs, rhs, starts,
+                               interpret=(mode == "interpret"))
+    return ref.grouped_matmul_ref(lhs, rhs, starts)
 
 
 def mamba_scan_full(cfg, p, u, dt, Bm, Cm):

@@ -22,6 +22,21 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None,
     return out.transpose(0, 2, 1, 3)
 
 
+def grouped_matmul_ref(lhs, rhs, starts):
+    """lhs (M, K) rows laid out by starts (G + 1,); rhs (G, K, N) -> (M, N):
+    row r of group g times rhs[g]; rows past starts[G] give zeros."""
+    G = rhs.shape[0]
+    row = jnp.arange(lhs.shape[0], dtype=jnp.int32)
+    group = jnp.searchsorted(starts[1:], row, side="right")
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32)
+    for g in range(G):
+        out = out + jnp.where((group == g)[:, None],
+                              jnp.matmul(lhs, rhs[g],
+                                         preferred_element_type=jnp.float32),
+                              0.0)
+    return out.astype(lhs.dtype)
+
+
 def mamba_scan_ref(cfg, p, u, h0=None):
     """Chunked selective-scan oracle (models/ssm.py)."""
     return ssm_scan_chunked(cfg, p, u, h0=h0)

@@ -34,14 +34,10 @@ from repro.optim import AdamWConfig
 VARIANTS = {
     "baseline": {},
     "mla_absorb": {"mla_absorb": True},
-    "moe_gather": {"moe_impl": "gather"},
-    "moe_chunk512": {"moe_chunk": 512},
-    "moe_gather512": {"moe_impl": "gather", "moe_chunk": 512},
     "bigchunk": {"attn_q_chunk": 2048, "attn_kv_chunk": 4096},
     "hugechunk": {"attn_q_chunk": 4096, "attn_kv_chunk": 8192},
     "mb8": {},
     "mb16": {},
-    "mb8_gather": {"moe_impl": "gather"},
     "noremat": {"train_remat": False},
     "causal_skip": {"attn_causal_skip": True},
     "noremat_skip": {"train_remat": False, "attn_causal_skip": True},

@@ -115,8 +115,22 @@ def _heads(x, n, d):
 
 def apply_self_attn(cfg: ModelConfig, p, x, *, pos0, mode: str,
                     cache=None, window: Optional[int] = None,
-                    causal: bool = True, cache_len: Optional[int] = None):
-    """Returns (out, new_cache). mode in {train, prefill, decode}."""
+                    causal: bool = True, cache_len: Optional[int] = None,
+                    full=None):
+    """Returns (out, new_cache). mode in {train, prefill, decode}.
+
+    Full-attention layers (``window`` None) rotate with the config's YaRN
+    frequencies where it has them. In a stack of mixed layer types
+    (``cfg.mixed_attention``), ``full`` is the layer's traced type flag:
+    the layer runs full attention where it is set, else the config's
+    sliding window."""
+    if full is not None:
+        def branch(w):
+            return lambda p, x, cache: apply_self_attn(
+                cfg, p, x, pos0=pos0, mode=mode, cache=cache, window=w,
+                causal=causal, cache_len=cache_len)
+        return jax.lax.cond(full, branch(None), branch(cfg.sliding_window),
+                            p, x, cache)
     B, S, _ = x.shape
     if cfg.use_mla:
         return _apply_mla(cfg, p, x, pos0=pos0, mode=mode, cache=cache,
@@ -134,8 +148,9 @@ def apply_self_attn(cfg: ModelConfig, p, x, *, pos0, mode: str,
         k = rms_norm_headwise(k, p["k_norm"])
     positions = pos0 + jnp.arange(S, dtype=jnp.int32)
     if cfg.use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        yarn = cfg.rope_yarn if window is None else None
+        q = apply_rope(q, positions, cfg.rope_theta, yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, yarn)
 
     new_cache = None
     if mode == "decode":

@@ -57,7 +57,7 @@ def plan_block(cfg: ModelConfig, kind: str, moe: bool = False):
 
 def apply_block(cfg: ModelConfig, kind: str, p, x, *, mode: str, pos0,
                 cache=None, kv_src=None, window: Optional[int] = None,
-                cache_len: Optional[int] = None):
+                cache_len: Optional[int] = None, full=None):
     if kind == "ssm":
         h, nc = apply_ssm(cfg, p["ssm"], apply_norm(cfg, p["norm"], x),
                           mode=mode, cache=cache)
@@ -75,7 +75,7 @@ def apply_block(cfg: ModelConfig, kind: str, p, x, *, mode: str, pos0,
         h, nc = apply_self_attn(cfg, p["attn"], apply_norm(cfg, p["norm1"], x),
                                 pos0=pos0, mode=mode, cache=cache,
                                 window=window, causal=causal,
-                                cache_len=cache_len)
+                                cache_len=cache_len, full=full)
         x = x + h
         y = apply_norm(cfg, p["norm2"], x)
         if "moe" in p:

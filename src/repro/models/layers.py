@@ -1,6 +1,7 @@
 """Shared primitive layers: norms, MLPs, rotary embeddings, positions."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -102,13 +103,37 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, D); positions: (S,) int32."""
+def yarn_freqs(head_dim: int, theta: float, yarn):
+    """YaRN inverse frequencies (Hugging Face ``_compute_yarn_parameters``,
+    truncated correction range): frequency j keeps its value below the
+    correction range of ``beta_fast`` .. ``beta_slow`` rotations over the
+    original context, is divided by ``factor`` above it, and ramps
+    linearly in between."""
+    def corr(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(corr(yarn.beta_fast)), 0)
+    hi = min(math.ceil(corr(yarn.beta_slow)), head_dim - 1)
+    inv = rope_freqs(head_dim, theta)
+    j = jnp.arange(head_dim // 2, dtype=jnp.float32)
+    ramp = jnp.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return inv / yarn.factor * ramp + inv * (1.0 - ramp)
+
+
+def apply_rope(x, positions, theta: float, yarn=None):
+    """x: (..., S, H, D); positions: (S,) int32. ``yarn`` (a
+    ``configs.base.RopeYarn``) rescales the frequencies and multiplies
+    cos and sin by its attention factor."""
     half = x.shape[-1] // 2
-    inv = rope_freqs(x.shape[-1], theta)                      # (half,)
+    inv = (rope_freqs(x.shape[-1], theta) if yarn is None
+           else yarn_freqs(x.shape[-1], theta, yarn))         # (half,)
     ang = positions.astype(jnp.float32)[:, None] * inv[None]  # (S, half)
     cos = jnp.cos(ang)[..., None, :]                          # (S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([xf1 * cos - xf2 * sin,

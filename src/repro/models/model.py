@@ -12,6 +12,9 @@ Public API:
 
 Stacks: homogeneous runs of layers grouped into scan-able "superblocks"
 (e.g. recurrentgemma (rec,rec,attn) periods; llama-vision 4x self + 1 cross).
+A stack whose layers mix full and sliding-window attention (``layer_types``)
+stays one scan of single layers, each choosing its type from a flag it is
+scanned with, so that every layer boundary is a scan boundary.
 """
 from __future__ import annotations
 
@@ -99,9 +102,21 @@ def enc_stack_defs(cfg: ModelConfig) -> Tuple[StackDef, ...]:
 def _sub_window(cfg: ModelConfig, sub: Sub) -> Optional[int]:
     if sub.kind == "attn" and cfg.block_pattern:
         return cfg.local_window          # griffin local attention
+    if sub.kind == "attn" and cfg.layer_types:
+        # a mixed stack picks per layer (_full_flags); else all alike
+        return None if cfg.mixed_attention else cfg.attention_windows()[0]
     if sub.kind in ("attn", "dec"):
         return cfg.sliding_window
     return None
+
+
+def _full_flags(cfg: ModelConfig, layer0: int, length: int):
+    """Per scan step of a mixed stack starting at decoder layer
+    ``layer0``: whether the layer attends in full (else in its window)."""
+    if not cfg.mixed_attention:
+        return None
+    wins = cfg.attention_windows()[layer0:layer0 + length]
+    return jnp.asarray([w is None for w in wins])
 
 
 # --------------------------------------------------------------------------
@@ -155,12 +170,20 @@ def n_params(cfg: ModelConfig) -> int:
 # --------------------------------------------------------------------------
 
 def _apply_stack(cfg: ModelConfig, sdef: StackDef, p_stack, x, *, mode: str,
-                 pos0, cache=None, kv_src=None, total_len=None, remat=False):
+                 pos0, cache=None, kv_src=None, total_len=None, remat=False,
+                 layer0: int = 0):
+    """Scan the stack's superblocks over x. ``layer0``: the decoder layer
+    index of the stack's first layer (a mixed stack's types follow it)."""
     want_cache = mode in ("prefill", "decode")
     has_cache_in = cache is not None
+    flags = _full_flags(cfg, layer0, sdef.length)
 
     def body(carry, xs):
         x, aux = carry
+        if flags is not None:
+            xs, full = xs
+        else:
+            full = None
         p_sl, c_sl = xs if has_cache_in else (xs, None)
         new_c = {}
         for sub in sdef.subs:
@@ -173,7 +196,7 @@ def _apply_stack(cfg: ModelConfig, sdef: StackDef, p_stack, x, *, mode: str,
                 x, nc, a = apply_block(cfg, sub.kind, p_sl[sub.name], x,
                                        mode=mode, pos0=pos0, cache=sub_cache,
                                        kv_src=kv_src, window=window,
-                                       cache_len=clen)
+                                       cache_len=clen, full=full)
                 aux = aux + a
                 if want_cache:
                     new_c[sub.name] = nc
@@ -197,6 +220,8 @@ def _apply_stack(cfg: ModelConfig, sdef: StackDef, p_stack, x, *, mode: str,
     if remat and mode == "train":
         body = jax.checkpoint(body)
     xs = (p_stack, cache) if has_cache_in else p_stack
+    if flags is not None:
+        xs = (xs, flags)
     (x, aux), new_cache = jax.lax.scan(body, (x, jnp.float32(0.0)), xs)
     return x, new_cache, aux
 
@@ -224,12 +249,14 @@ def _trunk(cfg: ModelConfig, params, x, *, mode, pos0, caches=None,
            kv_src=None, total_len=None, remat=False):
     aux_total = jnp.float32(0.0)
     new_caches = {} if mode in ("prefill", "decode") else None
+    layer0 = 0
     for s in stack_defs(cfg):
         cache_s = caches[s.name] if caches is not None else None
         x, nc, aux = _apply_stack(cfg, s, params["stacks"][s.name], x,
                                   mode=mode, pos0=pos0, cache=cache_s,
                                   kv_src=kv_src, total_len=total_len,
-                                  remat=remat)
+                                  remat=remat, layer0=layer0)
+        layer0 += s.layers
         aux_total = aux_total + aux
         if new_caches is not None:
             new_caches[s.name] = nc

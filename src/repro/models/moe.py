@@ -1,11 +1,20 @@
-"""Mixture-of-Experts layer: top-k routing, capacity-based dispatch
-(GShard-style one-hot einsum), shared experts, load-balance aux loss.
+"""Mixture-of-Experts layer: top-k routing over all experts, a dropless
+grouped matmul over the experts this chip holds, shared experts, and the
+load-balance aux loss.
 
-The dispatch/combine einsums are the SPMD-friendly baseline: with the
-expert dim sharded over "model" they lower to all-to-all style collectives
-under GSPMD. The sequence is processed in chunks (``moe_chunk``) so the
-dispatch tensor (B, chunk, E, C) stays bounded for 32k-token prefill.
-(EXPERIMENTS.md §Perf iterates on exactly this dispatch overhead.)
+Expert parallelism: the layer holds experts ``0 .. experts_held - 1`` of
+``n_experts`` (all of them by default). It routes every token over all
+``n_experts`` and returns its own experts' part of the result; the other
+chips' parts, and the exchange that would sum them, are not here. With
+every expert held that part is the whole layer.
+
+Dropless: every (token, slot) routed to a held expert is computed. The
+rows are sorted by expert into a buffer sized for the worst case (every
+token's slots on held experts), each expert's rows starting on a row tile
+of ``kernels/grouped_matmul.py``, and the three expert matmuls run as
+grouped matmuls over it (the Pallas kernel on a TPU, its oracle
+elsewhere), so no token is dropped and no expert computes rows it was
+not routed.
 """
 from __future__ import annotations
 
@@ -13,124 +22,90 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.grouped_matmul import padded_starts, rows_bound
 from repro.models.params import P
 from repro.models.layers import plan_mlp, apply_mlp
-
-MOE_CHUNK = 1024   # tokens per dispatch chunk (baseline; perf knob)
 
 
 def plan_moe(cfg: ModelConfig):
     d, f, E = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    held = cfg.resolved_experts_held
     plan = {
         "router": P((d, E), ("embed", "experts"), scale=d ** -0.5),
-        "w_gate": P((E, d, f), ("experts", "embed", "ff")),
-        "w_up": P((E, d, f), ("experts", "embed", "ff")),
-        "w_down": P((E, f, d), ("experts", "ff", "embed")),
+        "w_gate": P((held, d, f), ("experts", "embed", "ff")),
+        "w_up": P((held, d, f), ("experts", "embed", "ff")),
+        "w_down": P((held, f, d), ("experts", "ff", "embed")),
     }
     if cfg.n_shared_experts:
         plan["shared"] = plan_mlp(cfg, d_ff=cfg.moe_d_ff * cfg.n_shared_experts)
     return plan
 
 
-def _capacity(chunk: int, cfg: ModelConfig) -> int:
-    c = int(chunk * cfg.top_k * cfg.capacity_factor / cfg.n_experts)
-    return max(cfg.top_k, min(chunk, -(-c // 8) * 8))   # round up to 8
-
-
-def _route(cfg: ModelConfig, p, x):
-    """Shared top-k routing. Returns (top_p, top_e, pos, keep, aux)."""
-    B, T, d = x.shape
+def route(cfg: ModelConfig, p, x):
+    """Top-k routing over all experts. x: (T, d). Returns (weights (T, K)
+    normalized over the token's top k, experts (T, K), aux loss)."""
     E, K = cfg.n_experts, cfg.top_k
-    C = _capacity(T, cfg)
-    logits = (x.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                   # (B,T,E)
-    top_p, top_e = jax.lax.top_k(probs, K)                    # (B,T,K)
+    # full float32 products: the top-k choice flips on rounding near ties
+    logits = jnp.matmul(x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)                   # (T,E)
+    top_p, top_e = jax.lax.top_k(probs, K)                    # (T,K)
     top_p = top_p / jnp.maximum(jnp.sum(top_p, -1, keepdims=True), 1e-9)
-
-    sel = jax.nn.one_hot(top_e, E, dtype=jnp.float32)         # (B,T,K,E)
-    # position of each (token, slot) within its expert buffer
-    flat = sel.reshape(B, T * K, E)
-    pos_in_e = (jnp.cumsum(flat, axis=1) - flat).reshape(B, T, K, E)
-    pos = jnp.sum(pos_in_e * sel, axis=-1)                    # (B,T,K)
-    keep = (pos < C) & (jnp.sum(sel, -1) > 0)
     # load-balance loss terms (Switch-style): mean prob * mean assignment
-    me = jnp.mean(probs, axis=(0, 1))                         # (E,)
-    ce = jnp.mean(jnp.sum(sel, axis=2), axis=(0, 1))          # (E,)
+    me = jnp.mean(probs, axis=0)                              # (E,)
+    ce = jnp.mean(jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32),
+                          axis=1), axis=0)                    # (E,)
     aux = E * jnp.sum(me * ce) / K
-    return top_p, top_e, pos, keep, sel, aux
+    return top_p, top_e, aux
 
 
-def _experts(cfg, p, xe):
-    h_g = jnp.einsum("becd,edf->becf", xe, p["w_gate"])
-    h_u = jnp.einsum("becd,edf->becf", xe, p["w_up"])
-    h = jax.nn.silu(h_g) * h_u
-    return jnp.einsum("becf,efd->becd", h, p["w_down"])       # (B,E,C,d)
+def dispatch(cfg: ModelConfig, top_e):
+    """Where each (token, slot) of the held experts goes. Returns (rows
+    (T, K): its row in the expert buffer, or the buffer's size where its
+    expert is not held; token (R,): the token of each buffer row, or T for
+    padding; starts (held + 1,): each expert's first row). A slot's place
+    in its expert's rows is the count of earlier slots routed there (a
+    counting sort: no sort of the T K slots)."""
+    T, K = top_e.shape
+    held = cfg.resolved_experts_held
+    R = rows_bound(T * min(K, held), held)
+    e = top_e.reshape(-1)
+    key = jnp.where(e < held, e, held)                 # unheld: group held
+    onehot = jax.nn.one_hot(key, held + 1, dtype=jnp.int32)  # (T K, held+1)
+    before = jnp.cumsum(onehot, axis=0) - onehot
+    rank = jnp.take_along_axis(before, key[:, None], axis=1)[:, 0]
+    starts = padded_starts(jnp.sum(onehot[:, :held], axis=0))
+    rows = jnp.where(key < held, starts[jnp.minimum(key, held - 1)] + rank,
+                     R)
+    token = jnp.full((R,), T, jnp.int32).at[rows].set(
+        jnp.arange(T * K, dtype=jnp.int32) // K, mode="drop")
+    return rows.reshape(T, K), token, starts
 
 
-def _dispatch_chunk(cfg: ModelConfig, p, x):
-    """GShard-style one-hot einsum dispatch (baseline). x: (B,T,d)."""
-    B, T, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    C = _capacity(T, cfg)
-    top_p, top_e, pos, keep, sel, aux = _route(cfg, p, x)
-    pos_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32) * keep[..., None]
-    # combine weights (B,T,K,E,C) folded over K into dispatch/combine tensors
-    combine = jnp.einsum("btke,btkc,btk->btec", sel, pos_oh, top_p)
-    dispatch = jnp.einsum("btke,btkc->btec", sel, pos_oh)
+def held_experts(cfg: ModelConfig, p, x, top_p, top_e):
+    """The held experts' part of the layer, dropless. x: (T, d)."""
+    from repro.kernels import ops
 
-    xe = jnp.einsum("btec,btd->becd", dispatch.astype(x.dtype), x)  # (B,E,C,d)
-    ye = _experts(cfg, p, xe)
-    y = jnp.einsum("btec,becd->btd", combine.astype(x.dtype), ye)
-    return y, aux
-
-
-def _dispatch_chunk_gather(cfg: ModelConfig, p, x):
-    """Scatter/gather dispatch (optimized): no O(T*E*C*d) dispatch matmuls —
-    dispatch is a scatter-add into the expert buffer, combine is a gather.
-    Same capacity semantics as the einsum path (EXPERIMENTS.md §Perf)."""
-    B, T, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    C = _capacity(T, cfg)
-    top_p, top_e, pos, keep, sel, aux = _route(cfg, p, x)
-
-    slot = (top_e * C + pos.astype(jnp.int32)).astype(jnp.int32)  # (B,T,K)
-    slot = jnp.where(keep, slot, E * C)                       # overflow slot
-    b_idx = jnp.arange(B, dtype=jnp.int32)[:, None, None]
-    b_idx = jnp.broadcast_to(b_idx, slot.shape)               # (B,T,K)
-    vals = jnp.broadcast_to(x[:, :, None, :], (B, T, K, d))
-    xe_flat = jnp.zeros((B, E * C + 1, d), x.dtype).at[
-        b_idx, slot].add(vals)
-    xe = xe_flat[:, :E * C].reshape(B, E, C, d)
-    ye = _experts(cfg, p, xe)
-    ye_flat = jnp.concatenate(
-        [ye.reshape(B, E * C, d), jnp.zeros((B, 1, d), ye.dtype)], axis=1)
-    y_tk = ye_flat[b_idx, slot]                               # (B,T,K,d)
-    w = (top_p * keep).astype(x.dtype)
-    y = jnp.sum(y_tk * w[..., None], axis=2)
-    return y, aux
+    T, d = x.shape
+    rows, token, starts = dispatch(cfg, top_e)
+    xr = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[token]
+    g = ops.grouped_matmul(xr, p["w_gate"].astype(x.dtype), starts)
+    u = ops.grouped_matmul(xr, p["w_up"].astype(x.dtype), starts)
+    y = ops.grouped_matmul(jax.nn.silu(g) * u,
+                           p["w_down"].astype(x.dtype), starts)
+    # unheld slots read the zero row past the buffer
+    y = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)])
+    return jnp.einsum("tk,tkd->td", top_p.astype(y.dtype), y[rows])
 
 
 def apply_moe(cfg: ModelConfig, p, x):
-    """x: (B, S, d) -> (y, aux_loss). Chunks the sequence for dispatch."""
+    """x: (B, S, d) -> (y, aux_loss): the held experts' part of the routed
+    experts, plus the shared experts."""
     B, S, d = x.shape
-    dispatch_fn = (_dispatch_chunk_gather if cfg.moe_impl == "gather"
-                   else _dispatch_chunk)
-    chunk = min(cfg.moe_chunk or MOE_CHUNK, S)
-    if S % chunk != 0:
-        chunk = S  # fallback: single chunk (small/odd sequences)
-    n = S // chunk
-    if n == 1:
-        y, aux = dispatch_fn(cfg, p, x)
-    else:
-        xs = x.reshape(B, n, chunk, d).transpose(1, 0, 2, 3)
-
-        def body(_, xc):
-            yc, aux_c = dispatch_fn(cfg, p, xc)
-            return None, (yc, aux_c)
-
-        _, (ys, auxs) = jax.lax.scan(body, None, xs)
-        y = ys.transpose(1, 0, 2, 3).reshape(B, S, d)
-        aux = jnp.mean(auxs)
+    xf = x.reshape(B * S, d)
+    top_p, top_e, aux = route(cfg, p, xf)
+    y = held_experts(cfg, p, xf, top_p, top_e).reshape(B, S, d)
     if cfg.n_shared_experts:
         y = y + apply_mlp(cfg, p["shared"], x)
-    return y, aux * cfg.router_aux_weight
+    return y.astype(x.dtype), aux * cfg.router_aux_weight

@@ -19,6 +19,7 @@ dequantization is a single broadcast multiply.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, FrozenSet, Tuple
 
 import jax
@@ -28,9 +29,10 @@ MODES = ("w8wo", "w4", "w8a8")
 _QMAX = {8: 127, 4: 7}
 
 # dense-projection leaf names consumed via layers.dense (plain ``x @ w``
-# with w of shape (..., in, out)); einsum/reshape-consumed weights (MoE
-# experts, MLA up-projections, SSM/LRU mixers) and embeddings stay full
-# precision (quantize_tree additionally excludes the whole moe subtree).
+# with w of shape (..., in, out)); weights consumed otherwise (MoE experts
+# by the grouped matmul, MLA up-projections, SSM/LRU mixers) and
+# embeddings stay full precision (quantize_tree additionally excludes the
+# whole moe subtree).
 DENSE_WEIGHTS: FrozenSet[str] = frozenset(
     {"wq", "wk", "wv", "wo", "w_up", "w_gate", "w_down", "lm_head"})
 
@@ -148,20 +150,53 @@ def quantize_tree(tree: Dict, mode: str,
     models consume through ``layers.dense`` are converted, so
     einsum-consumed params keep their layout. The ``moe`` subtree is
     excluded wholesale — routed expert weights reuse the dense-MLP leaf
-    names but are consumed by the GShard dispatch einsums (models/moe.py).
+    names but are consumed in float by the grouped matmul (models/moe.py),
+    so every version tree can share one copy of them.
     Leading stacking dims pass through — scale and codes both keep the
     (layers, ...) prefix that scan/slicing expect.
     """
+    _check_mode(mode)
+    return _map_weights(tree, names, lambda w: quantize(w, mode))
+
+
+def quantize_trees(tree: Dict, modes: Tuple[str, ...],
+                   names: FrozenSet[str] = DENSE_WEIGHTS) -> Dict[str, Dict]:
+    """``quantize_tree`` for each of ``modes``, in one jitted call over the
+    weights it converts alone: every other leaf of each result is the
+    input tree's own array, shared and not copied (so the float experts of
+    a MoE model stay one copy across its versions)."""
+    for mode in modes:
+        _check_mode(mode)
+    weights = []
+    _map_weights(tree, names, weights.append)
+    codes = _quantize_weights(tuple(weights), tuple(modes))
+    out = {}
+    for mode in modes:
+        it = iter(codes[mode])
+        out[mode] = _map_weights(tree, names, lambda w: next(it))
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _quantize_weights(weights, modes):
+    return {mode: [quantize(w, mode) for w in weights] for mode in modes}
+
+
+def _check_mode(mode: str):
     if mode not in MODES:
         raise ValueError(f"unknown quant mode {mode!r}; known: {MODES}")
 
+
+def _map_weights(tree: Dict, names: FrozenSet[str], fn) -> Dict:
+    """``tree`` with ``fn`` applied to each weight ``quantize_tree``
+    converts, in a fixed order; every other leaf passes through as is."""
     def rec(node, key):
         if key == "moe":
             return node
         if isinstance(node, dict):
             return {k: rec(v, k) for k, v in node.items()}
         if key in names and getattr(node, "ndim", 0) >= 2:
-            return quantize(node, mode)
+            return fn(node)
         return node
     return rec(tree, "")
 

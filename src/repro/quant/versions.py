@@ -24,7 +24,7 @@ from typing import Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.quant.quantize import quantize, quantize_act, quantize_tree
+from repro.quant.quantize import quantize, quantize_act, quantize_trees
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,14 +132,15 @@ def accuracy_proxy(qv: QuantVersion, base_acc: float = 0.75,
 
 def build_version_params(cfg, params,
                          versions: Sequence[str] = DEFAULT_VERSIONS) -> Dict:
-    """Materialize the quantized param tree for each requested version.
+    """Materialize the param tree of each requested version.
 
     Returns {version_name: params} where bf16 aliases the input tree and
-    quantized versions share nothing with it (fresh QTensor leaves).
+    each quantized version holds fresh QTensor leaves for the weights
+    ``quantize_tree`` converts and shares every other leaf with the input
+    tree (``quantize_trees``: one jitted call over those weights alone).
     """
-    out = {}
-    for name in versions:
-        qv = get_version(name)
-        out[name] = params if qv.mode is None else quantize_tree(params,
-                                                                 qv.mode)
-    return out
+    qvs = {name: get_version(name) for name in versions}
+    trees = quantize_trees(params, tuple(dict.fromkeys(
+        qv.mode for qv in qvs.values() if qv.mode is not None)))
+    return {name: params if qv.mode is None else trees[qv.mode]
+            for name, qv in qvs.items()}

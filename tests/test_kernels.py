@@ -6,6 +6,8 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention import flash_attention, flash_plan
+from repro.kernels.grouped_matmul import (ROW_TILE, gmm_plan, grouped_matmul,
+                                          padded_starts, rows_bound)
 from repro.kernels.mamba_scan import mamba_scan
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels import ref
@@ -43,6 +45,48 @@ def test_flash_attention_sweep(B, H, HK, Sq, Skv, D, dtype, causal, window):
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
+
+
+@pytest.mark.parametrize("sizes", [
+    (0, 130, 5, 0, 256),       # empty groups; one crosses a tile boundary
+    (1, 1, 1),                 # every group a sliver of one tile
+    (0, 0, 0),                 # nothing routed here
+    (300,),                    # one group over three tiles
+])
+@pytest.mark.parametrize("K,N", [(256, 384), (384, 128)])
+def test_grouped_matmul_sweep(sizes, K, N):
+    """Live rows equal the oracle's; rows of no group are left alone."""
+    r = np.random.default_rng(len(sizes) * K + N)
+    G = len(sizes)
+    starts = padded_starts(jnp.asarray(sizes, jnp.int32))
+    M = rows_bound(sum(sizes), G)
+    x = jnp.asarray(r.normal(size=(M, K)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(G, K, N)), jnp.float32)
+    out = grouped_matmul(x, w, starts, interpret=True)
+    want = ref.grouped_matmul_ref(x, w, starts)
+    live = int(starts[-1])
+    assert live % ROW_TILE == 0 and live <= M
+    np.testing.assert_allclose(np.asarray(out)[:live],
+                               np.asarray(want)[:live], **TOL[jnp.float32])
+    for g, n in enumerate(sizes):
+        lo = int(starts[g])
+        np.testing.assert_allclose(
+            np.asarray(out)[lo:lo + n],
+            np.asarray(x)[lo:lo + n] @ np.asarray(w)[g], rtol=1e-4,
+            atol=1e-3)
+
+
+def test_gmm_plan_keeps_an_expert_whole():
+    """At Mellum's expert widths one weight block holds a whole expert
+    matrix, so the tiles of one expert fetch its weights once."""
+    for K, N in ((2304, 896), (896, 2304)):
+        plan = gmm_plan(18432, K, N)
+        assert (plan.tk, plan.tn) == (K, N)
+        assert plan.grid == (1, 18432 // ROW_TILE, 1)
+    # a wider expert is split along N and then K, in lane multiples
+    plan = gmm_plan(1024, 6144, 16384)
+    assert plan.tk % 128 == 0 and plan.tn % 128 == 0
+    assert plan.tk * plan.tn * 4 <= 9 << 20
 
 
 def _live_pairs(plan, Sq_pad, Skv_pad):

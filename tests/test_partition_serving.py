@@ -1,5 +1,5 @@
 """Split execution (head/tail) correctness + serving engine behaviour +
-hillclimb-variant numerical parity."""
+kernel-path and hillclimb-variant numerical parity."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +12,8 @@ from repro.serving import ServeConfig, ServingEngine, SplitServingEngine
 from tests.conftest import make_batch
 
 ARCHS_SPLIT = ["qwen2-0.5b", "falcon-mamba-7b", "recurrentgemma-2b",
-               "deepseek-v2-lite-16b", "llama-3.2-vision-90b"]
+               "deepseek-v2-lite-16b", "llama-3.2-vision-90b",
+               "mellum2-12b-a2.5b"]
 
 
 @pytest.mark.parametrize("arch", ARCHS_SPLIT)
@@ -76,13 +77,20 @@ def test_mla_absorb_decode_parity():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_moe_gather_parity():
-    cfg = get_config("mixtral-8x22b").reduced()
+@pytest.mark.parametrize("arch,held", [("mixtral-8x22b", 0),
+                                       ("mellum2-12b-a2.5b", 2)])
+def test_moe_kernel_path_parity(monkeypatch, arch, held):
+    """The dropless MoE on the grouped_matmul kernel (interpret mode) and
+    on its jnp oracle give the same logits, all experts held or some."""
+    from repro.kernels import ops
+    cfg = get_config(arch).reduced().with_overrides(experts_held=held)
     params = init(cfg, jax.random.key(1))
     batch = make_batch(cfg)
     del batch["targets"]
-    f1 = forward_logits(cfg, params, batch)
-    f2 = forward_logits(cfg.with_overrides(moe_impl="gather"), params, batch)
+    with ops.jnp_reference():
+        f1 = jax.jit(lambda p, b: forward_logits(cfg, p, b))(params, batch)
+    monkeypatch.setenv("REPRO_USE_PALLAS", "interpret")
+    f2 = jax.jit(lambda p, b: forward_logits(cfg, p, b))(params, batch)
     np.testing.assert_allclose(np.asarray(f2), np.asarray(f1),
                                rtol=2e-4, atol=2e-4)
 

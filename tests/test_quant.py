@@ -83,8 +83,8 @@ def test_quantize_tree_selects_dense_weights_only():
 
 def test_quantize_tree_skips_moe_experts():
     """Routed expert weights reuse the dense-MLP leaf names but are
-    einsum-consumed — they must stay full precision and the quantized
-    MoE model must still run."""
+    consumed in float by the grouped matmul — they must stay full
+    precision and the quantized MoE model must still run."""
     cfg = get_config("mixtral-8x22b").reduced()
     params = init(cfg, jax.random.key(1))
     qp = quantize_tree(params, "w8wo")
@@ -99,6 +99,40 @@ def test_quantize_tree_skips_moe_experts():
     ql = forward_logits(cfg, qp, batch)
     rel = float(jnp.linalg.norm(ql - full) / jnp.linalg.norm(full))
     assert rel < 0.1, rel
+
+
+@pytest.mark.parametrize("arch", ["mellum2-12b-a2.5b", "qwen2-0.5b"])
+def test_version_trees_share_the_leaves_they_do_not_quantize(arch):
+    """build_version_params quantizes in one jitted call over the weights
+    quantize_tree converts: each quantized tree holds exactly those as
+    QTensors, equal to jitted quantize_tree's, and every other leaf (the float
+    experts and router, embeddings, norms) is the float tree's own array,
+    so the versions hold one copy of it."""
+    cfg = get_config(arch).reduced()
+    params = init(cfg, jax.random.key(3))
+    trees = build_version_params(cfg, params, ("bf16", "w8", "w4"))
+    assert trees["bf16"] is params
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    for v in ("w8", "w4"):
+        want = jax.jit(quantize_tree, static_argnums=1)(
+            params, get_version(v).mode)
+        got = jax.tree_util.tree_flatten_with_path(
+            trees[v], is_leaf=lambda x: isinstance(x, QTensor))[0]
+        ref = jax.tree.leaves(want, is_leaf=lambda x: isinstance(x, QTensor))
+        assert [p for p, _ in got] == [p for p, _ in flat]
+        n_q = 0
+        for (path, leaf), (_, x), r in zip(got, flat, ref):
+            if isinstance(r, QTensor):
+                n_q += 1
+                assert isinstance(leaf, QTensor), path
+                np.testing.assert_array_equal(leaf.q, r.q)
+                np.testing.assert_array_equal(leaf.scale, r.scale)
+            else:
+                assert leaf is x, path
+        assert n_q > 0
+    if cfg.n_experts:
+        moe = trees["w8"]["stacks"]["main"]["blk"]["moe"]
+        assert moe is params["stacks"]["main"]["blk"]["moe"]
 
 
 def test_quantized_tree_bytes_shrink():

@@ -13,8 +13,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (flash_attention,
+                                           flash_attention_window)
 from repro.kernels.flash_decode import flash_decode
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.mamba_scan import mamba_scan
 from repro.kernels.ops import compiled_kernels
 from repro.kernels.quant_matmul import quant_matmul
@@ -84,11 +86,32 @@ F32, I8, I32 = jnp.float32, jnp.int8, jnp.int32
     ("flash_attention", flash_attention,
      [((1, 14, 1024, 64), F32), ((1, 2, 1024, 64), F32),
       ((1, 2, 1024, 64), F32)]),
+    # mellum2-12b-a2.5b, its cell's 8192-token prompt: the full layers'
+    # heads (32 query, 4 kv of 128) ...
+    ("flash_attention", flash_attention,
+     [((1, 32, 8192, 128), F32), ((1, 4, 8192, 128), F32),
+      ((1, 4, 8192, 128), F32)]),
+    # ... the held experts' gate / up and down matmuls over the dropless
+    # row buffer of 8192 tokens x 8 slots, 16 experts of 2304 x 896
+    ("grouped_matmul", grouped_matmul,
+     [((67584, 2304), F32), ((16, 2304, 896), F32), ((17,), I32)]),
+    ("grouped_matmul", grouped_matmul,
+     [((67584, 896), F32), ((16, 896, 2304), F32), ((17,), I32)]),
 ])
 def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
     compiled = _compile(functools.partial(fn, interpret=False), one_chip,
                         *shapes)
     assert _kernels(compiled) == {name}
+
+
+@pytest.mark.parametrize("S", [2048, 8192])
+def test_windowed_flash_compiles_for_v5e(one_chip, S):
+    """mellum2-12b-a2.5b's sliding layers: a window of 1024 keys."""
+    fn = functools.partial(flash_attention_window, window=1024,
+                           interpret=False)
+    compiled = _compile(fn, one_chip, ((1, 32, S, 128), F32),
+                        ((1, 4, S, 128), F32), ((1, 4, S, 128), F32))
+    assert _kernels(compiled) == {"flash_attention_window"}
 
 
 @pytest.fixture
@@ -121,6 +144,35 @@ def test_decode_step_runs_flash_decode(one_chip, tpu_dispatch):
         jax.ShapeDtypeStruct((4,), I32, sharding=one_chip),
         jax.ShapeDtypeStruct((), I32, sharding=one_chip)).compile()
     assert _kernels(compiled) == {"flash_decode"}
+
+
+@pytest.mark.parametrize("version,want", [
+    ("bf16", {"flash_attention", "flash_attention_window", "grouped_matmul"}),
+    ("w8", {"flash_attention", "flash_attention_window", "grouped_matmul",
+            "quant_matmul"}),
+])
+def test_mellum_split_tail_runs_kernels(one_chip, tpu_dispatch, version,
+                                        want):
+    """One period of mellum2-12b-a2.5b at its published widths, its 16
+    held experts, cut after the first layer: the tail's sliding and full
+    layers, experts and (w8) projections and head run the kernels."""
+    from repro.configs import get_config
+    from repro.core import partition
+    from repro.models import model as M
+    from repro.quant import build_version_params
+    cfg = get_config("mellum2-12b-a2.5b").with_overrides(n_layers=4,
+                                                         experts_held=16)
+    params = jax.eval_shape(lambda: M.init(cfg, jax.random.key(0)))
+    vp = jax.eval_shape(
+        lambda p: build_version_params(cfg, p, (version,))[version], params)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 2048), I32,
+                                            sharding=one_chip)}
+    act = jax.ShapeDtypeStruct((1, 2048, cfg.d_model), F32,
+                               sharding=one_chip)
+    fn = jax.jit(lambda p, a, b: partition.run_tail(cfg, p, a, b,
+                                                     ("main", 1)))
+    compiled = fn.lower(_placed(vp, one_chip), act, batch).compile()
+    assert _kernels(compiled) == want
 
 
 @pytest.mark.parametrize("version,want", [
